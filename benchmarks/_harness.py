@@ -143,6 +143,8 @@ def bench_main(bench: str, run: Callable[..., List[Row]], *,
     assert not (args.quick and args.full), "--quick and --full conflict"
     quick = (not args.full) if quick_default else args.quick
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     kw: Dict[str, Any] = {"quick": quick}
     if device_flag:
         kw["device"] = args.device

@@ -29,8 +29,7 @@ def main():
 
     # --- a larger random graph: the planner picks the backend -------------
     g2 = random_labeled_graph(800, avg_degree=3.0, n_labels=8, seed=1)
-    eng2 = Engine(g2, options=EngineOptions(materialize=False,
-                                            device_impl="reference"))
+    eng2 = Engine(g2, options=EngineOptions(materialize=False))
     q2 = random_query_from_graph(g2, n_nodes=5, qtype="H", seed=2)
     print(f"\n[random] query: {eng2.format(q2)}")
     print(f"[random] plan:  {eng2.explain(q2)}")

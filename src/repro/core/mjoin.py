@@ -52,7 +52,6 @@ path:
 from __future__ import annotations
 
 import time
-import warnings
 from dataclasses import dataclass, field
 from typing import Iterator, List, Optional, Sequence, Tuple
 
@@ -156,55 +155,39 @@ def _to_query_order(assign: np.ndarray, order: List[int],
 
 
 _DEVICE = None
-_DEVICE_FAILED = False
 
 
 def device_intersector():
-    """The jax/Pallas frontier executor, or None if jax is unavailable."""
-    global _DEVICE, _DEVICE_FAILED
-    if _DEVICE is None and not _DEVICE_FAILED:
-        try:
-            from ..jaxgm.frontier import DeviceIntersector
-            _DEVICE = DeviceIntersector()
-        except Exception as e:                      # pragma: no cover - env
-            _DEVICE_FAILED = True
-            warnings.warn(
-                f"frontier-device unavailable ({type(e).__name__}: {e}); "
-                f"falling back to the host frontier path", RuntimeWarning,
-                stacklevel=3)
+    """The process-wide jax/Pallas frontier slab executor (built on first
+    use).  A failed import or build raises: a device enum method never
+    silently runs on the host."""
+    global _DEVICE
+    if _DEVICE is None:
+        from ..jaxgm.frontier import DeviceIntersector
+        _DEVICE = DeviceIntersector()
     return _DEVICE
 
 
 def resident_intersector(rig: RIG, stats: Optional[MJoinStats] = None):
     """The RIG's device-resident executor, built (and uploaded) on first
     use and cached on ``rig.resident`` — one upload per RIG, shared by
-    every enumeration over it.  Returns None if jax is unavailable.
+    every enumeration over it.  A failed import or build raises.
 
     With ``stats``, upload accounting is recorded: ``resident_uploads``
     counts only *fresh* uploads (a cache hit contributes bytes but no
     upload), so engine counters reflect real transfers.
     """
-    global _DEVICE_FAILED
     res = getattr(rig, "resident", None)
     if res is not None and getattr(res, "closed", False):
         res = rig.resident = None       # torn down (evicted): rebuild
-    if res is None and not _DEVICE_FAILED:
-        try:
-            from ..jaxgm.frontier import ResidentIntersector
-            res = ResidentIntersector.build(rig)
-        except Exception as e:                      # pragma: no cover - env
-            _DEVICE_FAILED = True
-            warnings.warn(
-                f"frontier-device-resident unavailable "
-                f"({type(e).__name__}: {e}); falling back to the host "
-                f"frontier path", RuntimeWarning, stacklevel=3)
-            return None
-        rig.resident = res
+    if res is None:
+        from ..jaxgm.frontier import ResidentIntersector
+        res = rig.resident = ResidentIntersector.build(rig)
         if stats is not None:
             stats.resident_uploads += 1
             stats.resident_upload_s += res.upload_s
             stats.h2d_bytes += res.nbytes
-    if res is not None and stats is not None:
+    if stats is not None:
         stats.resident_bytes = res.nbytes
     return res
 
@@ -384,10 +367,7 @@ def _slab_intersect(rig: RIG, cs, slab: np.ndarray,
         t0 = time.perf_counter()
         try:
             with _XferDelta(intersector, stats):
-                if breaker is not None:
-                    acc, counts = breaker.call(lambda: intersector(rows))
-                else:
-                    acc, counts = intersector(rows)
+                acc, counts = intersector(rows, breaker=breaker)
         except (DeviceFailure, BreakerOpen):
             stats.device_s += time.perf_counter() - t0
             if "host-intersect" not in stats.degradations:
@@ -434,8 +414,6 @@ def _frontier_events(rig: RIG, order: List[int], cons, limit,
     intersector = None
     if device and not external:
         intersector = device_intersector()
-        if intersector is None:
-            stats.method = "frontier"                # jax missing: host path
 
     n_mat = 0
     count = 0
@@ -594,19 +572,12 @@ def _resident_frontier_events(rig: RIG, order: List[int], cons, limit,
     either the intersect or the expand step — degrades the remaining
     enumeration to the host gather + numpy intersect (``host-intersect``),
     and slabs below ``small_rows`` are host-routed pre-emptively (the
-    padded dispatch floor makes them device-unprofitable).  Without jax
-    the whole enumeration delegates to the host frontier path.
+    padded dispatch floor makes them device-unprofitable).  An executor
+    that cannot be built or compiled raises to the caller.
     """
     n = rig.query.n
     sizes = [rig.cos_size(qi) for qi in order]
     res = resident_intersector(rig, stats)
-    if res is None:
-        stats.method = "frontier"                    # jax missing: host path
-        yield from _frontier_events(rig, order, cons, limit, stats,
-                                    device=False, max_frontier=max_frontier,
-                                    mat_cap=mat_cap, slab_rows=slab_rows,
-                                    budget=budget, breaker=breaker)
-        return
 
     page_rows = max(1, max_frontier)
     state = {"count": 0, "n_mat": 0, "done": False, "dev_ok": True}
@@ -645,11 +616,8 @@ def _resident_frontier_events(rig: RIG, order: List[int], cons, limit,
             t0 = time.perf_counter()
             try:
                 with _XferDelta(res, stats):
-                    if breaker is not None:
-                        handle, counts = breaker.call(
-                            lambda: res.intersect(cs, slab, w64))
-                    else:
-                        handle, counts = res.intersect(cs, slab, w64)
+                    handle, counts = res.intersect(cs, slab, w64,
+                                                   breaker=breaker)
             except (DeviceFailure, BreakerOpen):
                 stats.device_s += time.perf_counter() - t0
                 _degrade()
@@ -668,11 +636,8 @@ def _resident_frontier_events(rig: RIG, order: List[int], cons, limit,
             t0 = time.perf_counter()
             try:
                 with _XferDelta(res, stats):
-                    if breaker is not None:
-                        rid, cid = breaker.call(
-                            lambda: res.expand(handle, n_i, want))
-                    else:
-                        rid, cid = res.expand(handle, n_i, want)
+                    rid, cid = res.expand(handle, n_i, want,
+                                          breaker=breaker)
             except (DeviceFailure, BreakerOpen):
                 stats.device_s += time.perf_counter() - t0
                 _degrade()
@@ -1278,11 +1243,7 @@ def mjoin_batched(jobs: Sequence[Tuple[RIG, List[int], Optional[int]]],
             d2h0 = getattr(isect0, "d2h_bytes", 0)
             if intersector is not None:
                 try:
-                    if breaker is not None:
-                        acc, counts = breaker.call(
-                            lambda: intersector(big))
-                    else:
-                        acc, counts = intersector(big)
+                    acc, counts = intersector(big, breaker=breaker)
                 except (DeviceFailure, BreakerOpen):
                     # degrade the whole batch for its remaining rounds:
                     # results are identical, just computed on the host

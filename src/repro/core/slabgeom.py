@@ -22,6 +22,7 @@ from typing import Tuple
 LANE_BYTES = 4          # kernels operate on uint32 lanes
 MIN_ROWS = 128          # F padding floor (bounds retraces to O(log F))
 MIN_LANES = 128         # W padding unit, in uint32 lanes
+RESIDENT_ROW_TILE = 8   # resident-matrix rows per aligned fetch
 
 
 def round_up(x: int, m: int) -> int:
@@ -59,6 +60,19 @@ def padded_rows_cap(max_bytes: int, k: int, w64: int, at_most: int) -> int:
     while fp < at_most and padded_slab_bytes(fp * 2, k, w64) <= max_bytes:
         fp *= 2
     return min(fp, at_most)
+
+
+def resident_rows(rows: int) -> int:
+    """Resident-matrix height for ``rows`` packed rows (edge rows plus
+    the all-zero row): a multiple of the 8-row tile the
+    ``gather_intersect`` kernel fetches."""
+    return round_up(rows, RESIDENT_ROW_TILE)
+
+
+def resident_matrix_bytes(rows: int, w_lanes: int) -> int:
+    """Device footprint of a resident RIG matrix of ``rows`` packed rows
+    (zero row included) over ``w_lanes`` uint32 lanes."""
+    return resident_rows(rows) * w_lanes * LANE_BYTES
 
 
 def resident_dispatch_bytes(f: int, k: int, w_lanes: int) -> int:

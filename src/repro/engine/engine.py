@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import itertools
 import time
-import warnings
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -74,11 +73,8 @@ _TPU_AVAILABLE: Optional[bool] = None
 def _tpu_available() -> bool:
     global _TPU_AVAILABLE
     if _TPU_AVAILABLE is None:
-        try:
-            import jax
-            _TPU_AVAILABLE = jax.default_backend() == "tpu"
-        except Exception:
-            _TPU_AVAILABLE = False
+        import jax
+        _TPU_AVAILABLE = jax.default_backend() == "tpu"
     return _TPU_AVAILABLE
 
 
@@ -335,7 +331,6 @@ class _Resident:
                                force_enum=options.force_enum)
         self._gm: Optional[GM] = None
         self._jgm = None
-        self._jgm_error: Optional[str] = None
 
     def gm(self) -> GM:
         if self._gm is None:
@@ -346,23 +341,15 @@ class _Resident:
         return self._gm
 
     def jgm(self):
-        """Device matcher, or ``None`` if the device path is unavailable
-        (then the caller re-routes to the host; the error is kept on
-        ``_jgm_error`` and surfaced through ``Engine.cache_info``)."""
-        if self._jgm is None and self._jgm_error is None:
-            try:
-                from ..jaxgm import JaxGM
-                o = self.options
-                self._jgm = JaxGM(self.ctx.graph, max_q=o.max_q,
-                                  max_e=o.max_e, capacity=o.capacity,
-                                  exact_sim=o.exact_sim, impl=o.device_impl,
-                                  use_transitive_reduction=False)
-            except Exception as e:
-                self._jgm_error = f"{type(e).__name__}: {e}"
-                warnings.warn(
-                    f"device matcher unavailable, queries re-route to the "
-                    f"host backend: {self._jgm_error}", RuntimeWarning,
-                    stacklevel=2)
+        """Device matcher (built and uploaded on first use).  A failed
+        build raises: a device-planned query never silently re-routes."""
+        if self._jgm is None:
+            from ..jaxgm import JaxGM
+            o = self.options
+            self._jgm = JaxGM(self.ctx.graph, max_q=o.max_q, max_e=o.max_e,
+                              capacity=o.capacity, exact_sim=o.exact_sim,
+                              impl=o.device_impl,
+                              use_transitive_reduction=False)
         return self._jgm
 
 
@@ -842,12 +829,8 @@ class Engine:
         the right graph."""
         opts.resident_executor = entry.resident
         if entry.plan.enum_method == "frontier-device":
-            try:
-                isect = device_intersector()
-            except Exception:
-                isect = None
-            if isect is not None:
-                isect.ledger_key = getattr(res.ctx.graph, "graph_key", "-")
+            device_intersector().ledger_key = getattr(res.ctx.graph,
+                                                      "graph_key", "-")
 
     def _run_host(self, res: _Resident, qr: PatternQuery, entry: _PlanEntry,
                   stats: EngineStats, materialize: bool,
@@ -1035,11 +1018,11 @@ class Engine:
         try:
             self._ensure_labels(res, stats, trace=trace, budget=b)
             t0 = time.perf_counter()
-            if entry.plan.backend == DEVICE and res.jgm() is not None:
+            if entry.plan.backend == DEVICE:
+                # compile outside the breaker: only the dispatch is retried
+                run = res.jgm().prepare(qr, materialize=mat)
                 try:
-                    dev = self.breaker.call(
-                        lambda: res.jgm().match(qr, materialize=mat),
-                        budget=b)
+                    dev = self.breaker.call(run, budget=b)
                     count, tuples = self._post_device(
                         res, qr, entry, stats, dev, mat, trace=trace,
                         dispatch_s=time.perf_counter() - t0, budget=b)
@@ -1238,10 +1221,9 @@ class Engine:
         jgm = res.jgm() if device_idx else None
         if jgm is not None and len(device_idx) >= 2:
             t0 = time.perf_counter()
+            run = jgm.prepare_batch([prepared[i][0] for i in device_idx])
             try:
-                batch = self.breaker.call(
-                    lambda: jgm.match_batch(
-                        [prepared[i][0] for i in device_idx]))
+                batch = self.breaker.call(run)
             except (DeviceFailure, BreakerOpen):
                 # whole-batch device loss: every member degrades to the
                 # host singles lane below (recompute, not repair)
@@ -1317,10 +1299,9 @@ class Engine:
             try:
                 if i in device_idx and jgm is not None:
                     # singleton device query: non-batched dispatch
+                    run = jgm.prepare(qr, materialize=False)
                     try:
-                        dev = self.breaker.call(
-                            lambda: jgm.match(qr, materialize=False),
-                            budget=b)
+                        dev = self.breaker.call(run, budget=b)
                         count, _ = self._post_device(
                             res, qr, entry, stats, dev, materialize=False,
                             trace=tr, dispatch_s=time.perf_counter() - t0,
@@ -1419,7 +1400,7 @@ class Engine:
         return render_trace(span, **kw)
 
     def cache_info(self) -> Dict[str, int]:
-        info = {
+        return {
             "plan_entries": len(self._plan_cache),
             "plan_hits": self._plan_cache.hits,
             "plan_misses": self._plan_cache.misses,
@@ -1427,8 +1408,3 @@ class Engine:
             "resident_graphs": len(self._residents),
             "label_builds": self.counters["label_builds"],
         }
-        errors = [r._jgm_error for r in self._residents.values()
-                  if r._jgm_error]
-        if errors:
-            info["device_errors"] = "; ".join(errors)
-        return info

@@ -39,7 +39,7 @@ from typing import List, Optional, Tuple
 from ..core.matcher import GMOptions
 from ..core.mjoin import DEFAULT_LIMIT
 from ..core.query import PatternQuery
-from ..core.slabgeom import round_up
+from ..core.slabgeom import resident_matrix_bytes, round_up
 from .stats import Calibration, EstimateRecord, GraphStats, RigStats
 
 __all__ = ["DeviceCaps", "Plan", "Planner"]
@@ -214,7 +214,7 @@ class Planner:
         ms = [self.stats.match_set_size(l) for l in q.labels]
         w_lanes = round_up(max((max(ms, default=0) + 31) // 32, 128), 128)
         rows = 1 + sum(ms[e.src] + ms[e.dst] for e in q.edges)
-        return rows * w_lanes * 4
+        return resident_matrix_bytes(rows, w_lanes)
 
     def _calibrated(self, quantity: str, est: float,
                     reasons: Optional[List[str]] = None) -> float:

@@ -17,9 +17,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import bitset as hostbits
 from ..core.graph import DataGraph
+from ..core.slabgeom import resident_rows
 from ..kernels import ops, packed
+from ..kernels.bitmm import K_BLOCK
 from ..obs.ledger import get_ledger
 
 PAD_LABEL = -2  # label id of padding nodes: never matches any query label
@@ -53,33 +54,48 @@ class DeviceGraph:
         return self.n_pad // 32
 
 
-def _repack_pad(words64: np.ndarray, n: int, n_pad: int) -> np.ndarray:
-    """Host uint64-packed rows over universe n -> uint32 rows over n_pad."""
-    dense = hostbits.unpack(words64, n)
-    rows = dense.shape[0]
-    out = np.zeros((n_pad, n_pad), dtype=bool)
-    out[:rows, :n] = dense
-    return np.asarray(packed.pack(jnp.asarray(out)))
+def _repack_pad(words64: np.ndarray, n_pad: int) -> np.ndarray:
+    """Host uint64-packed rows -> uint32 rows over n_pad, packed on the
+    host: little-endian uint64 words re-viewed as uint32 are bit-identical
+    to :func:`repro.kernels.packed.pack`, and bits past the universe are
+    zero, so nothing but the packed matrix is ever shipped."""
+    words32 = packed.pack_numpy_u64_to_u32(words64)
+    rows, w32 = words32.shape
+    out = np.zeros((n_pad, n_pad // 32), dtype=np.uint32)
+    w = min(w32, n_pad // 32)
+    out[:rows, :w] = words32[:, :w]
+    return out
+
+
+def padded_size(n: int, block: int) -> int:
+    """Universe size the device matcher pads ``n`` nodes to: a ``block``
+    multiple, and beyond one bitmm contraction block a multiple of it
+    (the packed operand's word blocks must be lane-aligned)."""
+    n_pad = -(-n // block) * block
+    if n_pad > K_BLOCK:
+        n_pad = -(-n_pad // K_BLOCK) * K_BLOCK
+    return n_pad
 
 
 def from_host(graph: DataGraph, block: int = 512,
               closure_on_device: bool = False,
               impl: str = "auto") -> DeviceGraph:
     n = graph.n
-    n_pad = ((n + block - 1) // block) * block
+    n_pad = padded_size(n, block)
     labels = np.full(n_pad, PAD_LABEL, dtype=np.int32)
     labels[:n] = graph.labels
 
-    adj = _repack_pad(graph.adj_bits(), n, n_pad)
-    adj_t = _repack_pad(graph.adj_bits_t(), n, n_pad)
+    adj = _repack_pad(graph.adj_bits(), n_pad)
+    adj_t = _repack_pad(graph.adj_bits_t(), n_pad)
     if closure_on_device:
         reach = np.asarray(ops.transitive_closure(jnp.asarray(adj), impl=impl))
-        dense = np.asarray(packed.unpack(jnp.asarray(reach), n_pad))
-        reach_t = np.asarray(packed.pack(jnp.asarray(dense.T)))
+        dense = np.unpackbits(reach.view(np.uint8), axis=1,
+                              bitorder="little").astype(bool)
+        reach_t = packed.pack_host(dense.T)
     else:
         ridx = graph.reachability()
-        reach = _repack_pad(ridx.reach_bits, n, n_pad)
-        reach_t = _repack_pad(ridx.bits_t(), n, n_pad)
+        reach = _repack_pad(ridx.reach_bits, n_pad)
+        reach_t = _repack_pad(ridx.bits_t(), n_pad)
     dg = DeviceGraph(n=n, n_pad=n_pad,
                      labels=jnp.asarray(labels),
                      adj=jnp.asarray(adj), adj_t=jnp.asarray(adj_t),
@@ -91,13 +107,6 @@ def from_host(graph: DataGraph, block: int = 512,
     return dg
 
 
-def stacked_matrices(dg: DeviceGraph) -> jax.Array:
-    """(4, n_pad, W) stacked [adj, reach, adj_t, reach_t] — lets the
-    enumerator pick the operand with one flat gather:
-    matrix id = 2 * is_backward + (kind == DESC)."""
-    return jnp.stack([dg.adj, dg.reach, dg.adj_t, dg.reach_t], axis=0)
-
-
 def pack_resident_rig(rig):
     """Concatenate a RIG's per-edge packed adjacency into one uint32
     matrix for the resident gather-intersect path.
@@ -107,23 +116,25 @@ def pack_resident_rig(rig):
     stacked row-wise into ``(R, W)`` with ``W`` = the widest edge's lane
     count rounded to 128; rows are zero-extended beyond their true width,
     so AND/popcount over the common width is exact.  A dedicated all-zero
-    row is appended last — index padding targets it so padded dispatch
-    rows contribute nothing.
+    row follows the edge rows — index padding targets it so padded
+    dispatch rows contribute nothing — and R is padded with zero rows to
+    a multiple of 8, the tile the ``gather_intersect`` kernel fetches.
 
     Returns ``(matrix32, fwd_off, bwd_off, zero_row)``: constraint row
     ``(edge e, forward, local src id i)`` lives at ``fwd_off[e] + i``
     (``bwd_off[e] + i`` for backward rows).
 
-    Resident footprint: ``(Σ_e |cos(src_e)| + |cos(dst_e)| + 1) * W * 4``
-    bytes — linear in RIG nodes per edge, not in enumerated frontiers.
+    Resident footprint: ``round8(Σ_e |cos(src_e)| + |cos(dst_e)| + 1) * W
+    * 4`` bytes (:func:`repro.core.slabgeom.resident_matrix_bytes`) —
+    linear in RIG nodes per edge, not in enumerated frontiers.
     """
     mats = list(rig.fwd) + list(rig.bwd)
     w_lanes = 128
     for m in mats:
         w_lanes = max(w_lanes, 2 * m.shape[1])
     w_lanes = -(-w_lanes // 128) * 128
-    rows = sum(m.shape[0] for m in mats) + 1          # + the all-zero row
-    matrix = np.zeros((rows, w_lanes), dtype=np.uint32)
+    zero_row = sum(m.shape[0] for m in mats)
+    matrix = np.zeros((resident_rows(zero_row + 1), w_lanes), dtype=np.uint32)
     fwd_off: list = []
     bwd_off: list = []
     off = 0
@@ -135,4 +146,4 @@ def pack_resident_rig(rig):
                 matrix[off:off + s, :2 * w64] = np.ascontiguousarray(
                     m).view(np.uint32).reshape(s, 2 * w64)
             off += s
-    return matrix, fwd_off, bwd_off, rows - 1
+    return matrix, fwd_off, bwd_off, zero_row

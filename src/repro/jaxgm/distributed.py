@@ -34,7 +34,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..kernels import packed
 from ..kernels.ops import _bitmm_blocked
-from .compat import shard_map
 from .device_graph import DeviceGraph
 from .encoding import QueryTensor
 
@@ -143,7 +142,7 @@ def sharded_double_simulation(mats: jax.Array, labels: jax.Array,
                              pack_y=pack_y)
     qt_specs = jax.tree.map(lambda _: P(), qts)
 
-    pass_sharded = shard_map(
+    pass_sharded = jax.shard_map(
         lambda m, f, q: body(m, f, q),
         mesh=mesh,
         in_specs=(P(None, row_axes, col), P(None, None, col), qt_specs),
@@ -215,7 +214,7 @@ def gm_serve_step(mats: jax.Array, labels: jax.Array, qts: QueryTensor,
         return jax.lax.psum(partial_counts, col)         # sum node shards
 
     qt_specs = jax.tree.map(lambda _: P(), qts)
-    edge_counts = shard_map(
+    edge_counts = jax.shard_map(
         count_body, mesh=mesh,
         in_specs=(P(None, row_axes, col), P(None, None, col), qt_specs),
         out_specs=P(),
@@ -245,7 +244,7 @@ def gm_serve_step(mats: jax.Array, labels: jax.Array, qts: QueryTensor,
         out = jnp.take_along_axis(gid_all, take, axis=2)
         return out.astype(jnp.int32)
 
-    candidates = shard_map(
+    candidates = jax.shard_map(
         compact_body, mesh=mesh,
         in_specs=(P(None, None, col),),
         out_specs=P(),                      # replicated (it is small)

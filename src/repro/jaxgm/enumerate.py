@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import packed
-from .device_graph import DeviceGraph, stacked_matrices
+from .device_graph import DeviceGraph
 from .encoding import PAD, QueryTensor
 
 
@@ -53,7 +53,9 @@ def mjoin_count(dg: DeviceGraph, qt: QueryTensor, fb: jax.Array,
     """
     np_, max_q, max_e = dg.n_pad, qt.max_q, qt.max_e
     w = dg.n_words
-    mats_flat = stacked_matrices(dg).reshape(4 * np_, w)
+    # operand by matrix id = 2 * is_backward + (kind == DESC); gathered
+    # per matrix and selected, so no stacked copy of the graph is made
+    mats = (dg.adj, dg.reach, dg.adj_t, dg.reach_t)
     fb_words = packed.pack(fb)                       # (max_q, W)
     inv = _inverse_order(order, max_q)
 
@@ -67,22 +69,29 @@ def mjoin_count(dg: DeviceGraph, qt: QueryTensor, fb: jax.Array,
         active = i < qt.n_nodes
         is_last = i == qt.n_nodes - 1
 
-        cand = jnp.broadcast_to(jnp.take(fb_words, qi, axis=0)[None, :],
-                                (capacity, w))
-        for e in range(max_e):                       # static edges
+        def constrain(e, cand):
+            """AND in edge e's adjacency row when it binds level i."""
             src, dst, kind = qt.edge_src[e], qt.edge_dst[e], qt.edge_kind[e]
             valid = kind >= 0
             psrc = jnp.take(inv, jnp.clip(src, 0, max_q - 1))
             pdst = jnp.take(inv, jnp.clip(dst, 0, max_q - 1))
             f_app = valid & (pdst == i) & (psrc < i)   # src bound -> fwd row
             b_app = valid & (psrc == i) & (pdst < i)   # dst bound -> bwd row
-            applies = f_app | b_app
             jpos = jnp.where(f_app, psrc, pdst)
             mat_id = jnp.where(f_app, 0, 2) + jnp.clip(kind, 0, 1)
             t_col = jnp.take(assign, jnp.clip(jpos, 0, max_q - 1), axis=1)
-            row_idx = mat_id * np_ + jnp.clip(t_col, 0, np_ - 1)
-            rows = jnp.take(mats_flat, row_idx, axis=0)          # (F, W)
-            cand = jnp.where(applies, cand & rows, cand)
+            row_idx = jnp.clip(t_col, 0, np_ - 1)
+            rows = jnp.take(mats[0], row_idx, axis=0)            # (F, W)
+            for m in range(1, 4):
+                rows = jnp.where(mat_id == m,
+                                 jnp.take(mats[m], row_idx, axis=0), rows)
+            return jnp.where(f_app | b_app, cand & rows, cand)
+
+        # a rolled loop: one edge's gathers live at a time
+        cand = jax.lax.fori_loop(
+            0, max_e, constrain,
+            jnp.broadcast_to(jnp.take(fb_words, qi, axis=0)[None, :],
+                             (capacity, w)))
 
         cand = jnp.where(alive[:, None], cand, jnp.uint32(0))
         counts = packed.popcount(cand).sum(axis=1)               # (F,)
@@ -90,12 +99,9 @@ def mjoin_count(dg: DeviceGraph, qt: QueryTensor, fb: jax.Array,
         total = total + jnp.where(active & is_last, level_total, 0)
 
         # --- expand (all non-last active levels; last too if materializing)
-        bits = packed.unpack(cand, np_)                          # (F, Np)
-        flat = bits.reshape(-1)
-        take = jnp.argsort(~flat, stable=True)[:capacity]
-        valid_new = jnp.take(flat, take)
-        parent = (take // np_).astype(jnp.int32)
-        node = (take % np_).astype(jnp.int32)
+        # the first `capacity` set bits in row-major order, selected from
+        # the packed words (no dense (F, Np) unpack or sort)
+        parent, node, valid_new = packed.first_set_bits(cand, capacity)
         new_assign = jnp.take(assign, parent, axis=0).at[:, i].set(
             jnp.where(valid_new, node, PAD))
         do_expand = active & (~is_last | jnp.bool_(materialize))

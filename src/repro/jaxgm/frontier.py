@@ -52,11 +52,12 @@ import numpy as np
 
 from ..core.slabgeom import (padded_slab_bytes, padded_slab_shape,
                              pow2_at_least, resident_dispatch_bytes,
-                             resident_rows_cap, round_up)
+                             resident_rows_cap)
 from ..kernels.gather_intersect import (expand_pairs, gather_intersect_pallas,
                                         gather_intersect_xla)
 from ..kernels.intersect import intersect_pallas, intersect_xla
 from ..obs.ledger import get_ledger
+from ..robust.breaker import governed
 
 __all__ = ["DeviceIntersector", "ResidentIntersector", "resolve_mode",
            "DEFAULT_MODE", "resident_fingerprint"]
@@ -133,8 +134,12 @@ class DeviceIntersector:
             self._compiled[key] = fn
         return fn
 
-    def __call__(self, rows_u64: np.ndarray
+    def __call__(self, rows_u64: np.ndarray, breaker=None
                  ) -> Tuple[np.ndarray, np.ndarray]:
+        """Pad, compile (once per shape) and dispatch one slab.  The
+        executor is built before the dispatch, outside ``breaker``: a
+        kernel that does not compile raises to the caller instead of
+        being retried and degraded like a failed dispatch."""
         f, k, w64 = rows_u64.shape
         w = 2 * w64                                     # uint32 lanes
         rows = np.ascontiguousarray(rows_u64).view(np.uint32)
@@ -148,18 +153,24 @@ class DeviceIntersector:
             rows = padded
         self.peak_slab_bytes = max(self.peak_slab_bytes,
                                    padded_slab_bytes(f, k, w64))
-        # rows is padded, so rows.nbytes == padded_slab_bytes(f, k, w64):
-        # the ledger charge equals the dispatched bytes by construction
-        self.h2d_bytes += rows.nbytes
-        get_ledger().transfers.h2d("slab_ship", rows.nbytes,
-                                   self.ledger_key)
         fn = self._executor(fp, kp, wp)
-        # fence with block_until_ready so kernel_s is true device time, not
-        # async-dispatch latency (the conversion below would hide the wait)
-        t0 = time.perf_counter()
-        and32, counts = fn(jnp.asarray(rows))
-        jax.block_until_ready((and32, counts))
-        self.kernel_s += time.perf_counter() - t0
+
+        def dispatch():
+            # rows is padded, so rows.nbytes == padded_slab_bytes(f, k,
+            # w64): the ledger charge equals the dispatched bytes by
+            # construction (charged per attempt: retries ship again)
+            self.h2d_bytes += rows.nbytes
+            get_ledger().transfers.h2d("slab_ship", rows.nbytes,
+                                       self.ledger_key)
+            # fence with block_until_ready so kernel_s is true device
+            # time, not async-dispatch latency
+            t0 = time.perf_counter()
+            out = fn(jnp.asarray(rows))
+            jax.block_until_ready(out)
+            self.kernel_s += time.perf_counter() - t0
+            return out
+
+        and32, counts = governed(breaker, dispatch)
         self.calls += 1
         and_np = np.asarray(and32)
         counts_np = np.asarray(counts)
@@ -189,8 +200,8 @@ class ResidentIntersector:
     matrix footprint and ``upload_s`` the fenced one-time upload.
     """
 
-    # device->host pair pages are sliced from this bucket granularity so
-    # expand retraces stay bounded
+    # smallest device->host pair page; larger pages double from here so
+    # expand executors stay few per level
     PAGE_BUCKET = 1024
 
     def __init__(self, matrix32: np.ndarray, fwd_off: List[int],
@@ -293,7 +304,7 @@ class ResidentIntersector:
         return fn
 
     # ------------------------------------------------------------------ API
-    def intersect(self, cs, slab: np.ndarray, w64: int
+    def intersect(self, cs, slab: np.ndarray, w64: int, breaker=None
                   ) -> Tuple[_ResidentSlab, np.ndarray]:
         """One level dispatch for one frontier slab.
 
@@ -301,7 +312,8 @@ class ResidentIntersector:
         (as built by ``repro.core.mjoin._constraints``), ``slab`` the
         ``(F, i)`` frontier rows, ``w64`` the level's packed row width in
         uint64 words.  Ships only the ``(F, K)`` int32 index matrix;
-        returns the on-device AND rows (handle) plus host popcounts.
+        returns the on-device AND rows (handle) plus host popcounts.  The
+        executor is compiled before the dispatch, outside ``breaker``.
         """
         f, k = len(slab), len(cs)
         idx = np.empty((f, k), dtype=np.int32)
@@ -316,17 +328,23 @@ class ResidentIntersector:
             idx = np.vstack([idx, pad])
         w32 = 2 * w64
         fn = self._intersect_exec(fp, k, w32)
-        # idx is padded, so idx.nbytes == pow2_at_least(f) * k * 4: charged
-        # bytes equal shipped bytes
-        self.h2d_bytes += idx.nbytes
-        get_ledger().transfers.h2d("index_vectors", idx.nbytes, self.key)
+
+        def dispatch():
+            # idx is padded, so idx.nbytes == pow2_at_least(f) * k * 4:
+            # charged bytes equal shipped bytes
+            self.h2d_bytes += idx.nbytes
+            get_ledger().transfers.h2d("index_vectors", idx.nbytes,
+                                       self.key)
+            t0 = time.perf_counter()
+            out = fn(self.matrix, jnp.asarray(idx))
+            jax.block_until_ready(out)
+            self.kernel_s += time.perf_counter() - t0
+            return out
+
         self.peak_dispatch_bytes = max(
             self.peak_dispatch_bytes,
             resident_dispatch_bytes(f, k, self.w_lanes))
-        t0 = time.perf_counter()
-        acc, counts = fn(self.matrix, jnp.asarray(idx))
-        jax.block_until_ready((acc, counts))
-        self.kernel_s += time.perf_counter() - t0
+        acc, counts = governed(breaker, dispatch)
         self.calls += 1
         counts_np = np.asarray(counts)
         self.d2h_bytes += counts_np.nbytes
@@ -335,11 +353,12 @@ class ResidentIntersector:
         return (_ResidentSlab(acc, f),
                 counts_np[:f].astype(np.int64))
 
-    def expand(self, handle: _ResidentSlab, n_i: int, want: int
-               ) -> Tuple[np.ndarray, np.ndarray]:
+    def expand(self, handle: _ResidentSlab, n_i: int, want: int,
+               breaker=None) -> Tuple[np.ndarray, np.ndarray]:
         """First ``want`` set-bit ``(row, column)`` pairs of a dispatched
         slab, in row-major (= lexicographic) order — computed on device,
-        shipped as one compact page."""
+        shipped as one compact page.  Page sizes are bucketed to powers of
+        two (>= ``PAGE_BUCKET``) so each level compiles O(log) executors."""
         if want <= 0:
             z = np.empty(0, dtype=np.int64)
             return z, z
@@ -349,25 +368,34 @@ class ResidentIntersector:
             # an order of magnitude faster than XLA's serialized nonzero
             # lowering.  Pallas/interpret modes extract on device, where
             # shipping the compact (row, col) page beats shipping rows.
-            t0 = time.perf_counter()
-            lanes = (n_i + 31) // 32          # fetch only the live lanes
-            rows = np.asarray(handle.acc[:handle.f, :lanes])
-            self.d2h_bytes += rows.nbytes
-            get_ledger().transfers.d2h("pair_extract_d2h", rows.nbytes,
-                                       self.key)
+            def dispatch():
+                t0 = time.perf_counter()
+                lanes = (n_i + 31) // 32      # fetch only the live lanes
+                rows = np.asarray(handle.acc[:handle.f, :lanes])
+                self.d2h_bytes += rows.nbytes
+                get_ledger().transfers.d2h("pair_extract_d2h", rows.nbytes,
+                                           self.key)
+                self.kernel_s += time.perf_counter() - t0
+                return rows
+
+            rows = governed(breaker, dispatch)
             bits = np.unpackbits(np.ascontiguousarray(rows).view(np.uint8),
                                  axis=1, bitorder="little")[:, :n_i]
             rid, cid = np.nonzero(bits)
-            self.kernel_s += time.perf_counter() - t0
             self.expand_calls += 1
             return rid[:want].astype(np.int64), cid[:want].astype(np.int64)
-        size = round_up(want, self.PAGE_BUCKET)
+        size = pow2_at_least(want, self.PAGE_BUCKET)
         fp, w32 = handle.acc.shape
         fn = self._expand_exec(int(fp), int(w32), n_i, size)
-        t0 = time.perf_counter()
-        rid, cid = fn(handle.acc)
-        jax.block_until_ready((rid, cid))
-        self.kernel_s += time.perf_counter() - t0
+
+        def dispatch():
+            t0 = time.perf_counter()
+            out = fn(handle.acc)
+            jax.block_until_ready(out)
+            self.kernel_s += time.perf_counter() - t0
+            return out
+
+        rid, cid = governed(breaker, dispatch)
         self.expand_calls += 1
         rid_np, cid_np = np.asarray(rid), np.asarray(cid)
         page = rid_np.nbytes + cid_np.nbytes   # full pages ship, then slice

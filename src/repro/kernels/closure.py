@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 WORD = 32
 
 
@@ -83,7 +81,7 @@ def closure_step_pallas(r_words: jax.Array, *, bm: int = 256, bn: int = 1024,
         out_specs=pl.BlockSpec((bm, wn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, wn_total), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r_words, r_words, r_words)

@@ -10,13 +10,17 @@ device.  Compared to the ``intersect`` kernel it replaces the host-side
 ``(F, K, W)`` gather + transfer with an ``(F, K)`` index upload: the slab
 traffic drops from ``F*K*W*4`` bytes to ``F*K*4`` bytes per dispatch.
 
-Grid: ``(F/bf,)`` with the index block scalar-prefetched into SMEM so row
-addresses are known before the body runs; each program issues ``bf*K``
-async copies from the resident matrix (``pltpu.ANY`` — HBM for large
-matrices) into a VMEM scratch, waits, then reduces.  K is static and
-unrolled.  Outputs stay padded to the grid (callers slice rows on the
-host side); AND rows are sliced to the level's true lane count ``w32``
-inside the jit so the device-to-host copy is exact.
+Grid: ``(F/bf,)``.  Each program reads its ``bf*K`` row indices from an
+SMEM block and issues one async copy per index from the resident matrix
+(``pltpu.ANY`` — HBM for large matrices) into a VMEM scratch.  A DMA
+slice must be aligned to the (8, 128) tiling, so each copy fetches the
+8-row tile that holds the wanted row; the row itself is then selected in
+VMEM (a sublane mask + sum, exact because the other seven rows are
+masked to zero).  The resident matrix therefore has a multiple of 8 rows
+(:func:`repro.jaxgm.device_graph.pack_resident_rig` pads it).  K is
+static and unrolled.  Outputs stay padded to the grid (callers slice rows
+on the host side); AND rows are sliced to the level's true lane count
+``w32`` inside the jit so the device-to-host copy is exact.
 
 The ``gather_intersect_xla`` variant is the same contraction expressed as
 a plain XLA gather + AND + ``population_count`` — the default executor on
@@ -31,77 +35,89 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .packed import WORD, first_set_bits
 
-def _gather_intersect_kernel(idx_ref, mat_ref, and_ref, cnt_ref, rows_vmem,
+
+SUBLANES = 8                 # rows per aligned resident-matrix fetch
+
+
+def _gather_intersect_kernel(idx_ref, mat_ref, and_ref, cnt_ref, tiles,
                              sems, *, bf: int, k_rows: int):
-    i = pl.program_id(0)
-    base = i * bf
-    # one DMA per (frontier row, constraint): resident row idx[base+r, c]
-    # lands in scratch slot r*K + c.  Start all copies, then wait — the
-    # issue loop overlaps with in-flight transfers.
+    # one DMA per (frontier row, constraint): the aligned 8-row tile that
+    # holds resident row idx[r*K + c] lands in scratch slot r*K + c.
+    # Start all copies, then wait — the issue loop overlaps with in-flight
+    # transfers.
     copies = []
-    for r in range(bf):
-        for c in range(k_rows):
-            row = idx_ref[base + r, c]
-            slot = r * k_rows + c
-            copies.append(pltpu.make_async_copy(
-                mat_ref.at[pl.ds(row, 1), :],
-                rows_vmem.at[pl.ds(slot, 1), :],
-                sems.at[slot]))
+    for slot in range(bf * k_rows):
+        start = pl.multiple_of(idx_ref[0, slot] // SUBLANES * SUBLANES,
+                               SUBLANES)
+        copies.append(pltpu.make_async_copy(
+            mat_ref.at[pl.ds(start, SUBLANES), :], tiles.at[slot],
+            sems.at[slot]))
     for dma in copies:
         dma.start()
     for dma in copies:
         dma.wait()
-    tile = rows_vmem[...].reshape(bf, k_rows, rows_vmem.shape[-1])
-    acc = tile[:, 0]
-    for c in range(1, k_rows):                 # K is static and small
-        acc = acc & tile[:, c]
-    and_ref[...] = acc
-    pc = jax.lax.population_count(acc).astype(jnp.int32)
-    cnt_ref[...] = pc.sum(axis=1, keepdims=True)
+    sub = jax.lax.broadcasted_iota(jnp.int32, tiles.shape[1:], 0)
+    for r in range(bf):
+        acc = None
+        for c in range(k_rows):                # K is static and small
+            slot = r * k_rows + c
+            tile = jax.lax.bitcast_convert_type(tiles[slot], jnp.int32)
+            picked = jnp.where(sub == idx_ref[0, slot] % SUBLANES, tile, 0)
+            row = jnp.sum(picked, axis=0, keepdims=True)       # (1, W)
+            acc = row if acc is None else acc & row
+        acc = jax.lax.bitcast_convert_type(acc, jnp.uint32)
+        and_ref[r:r + 1, :] = acc
+        pc = jax.lax.population_count(acc).astype(jnp.int32)
+        cnt_ref[r:r + 1, :] = pc.sum(axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("w32", "bf", "interpret"))
 def gather_intersect_pallas(matrix: jax.Array, idx: jax.Array, *, w32: int,
                             bf: int = 8, interpret: bool = False):
-    """matrix: uint32 (R, W) resident; idx: int32 (F, K) row indices ->
-    (and_rows uint32 (Fp, w32), counts int32 (Fp,)) with Fp = F rounded up
-    to ``bf`` (callers pad F themselves to bound retraces and slice rows
-    back; padding index rows should point at an all-zero resident row).
+    """matrix: uint32 (R, W) resident, R a multiple of 8; idx: int32 (F, K)
+    row indices -> (and_rows uint32 (Fp, w32), counts int32 (Fp,)) with
+    Fp = F rounded up to ``bf`` (callers pad F themselves to bound
+    retraces and slice rows back; padding index rows should point at an
+    all-zero resident row).
 
     ``w32`` is the level's true lane count: AND rows are cut to it before
     leaving the device.  Counts are exact regardless — resident rows are
     zero beyond their own true width, so padding lanes AND to zero.
     """
     f, k_rows = idx.shape
-    _, w = matrix.shape
+    r, w = matrix.shape
+    assert r % SUBLANES == 0, f"resident rows {r} not a multiple of 8"
     fp = -(-f // bf) * bf
     if fp != f:
         idx = jnp.pad(idx, ((0, fp - f), (0, 0)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(fp // bf,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=[
-            pl.BlockSpec((bf, w), lambda i, idx_ref: (i, 0)),
-            pl.BlockSpec((bf, 1), lambda i, idx_ref: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bf * k_rows, w), jnp.uint32),
-            pltpu.SemaphoreType.DMA((bf * k_rows,)),
-        ])
     and_rows, counts = pl.pallas_call(
         functools.partial(_gather_intersect_kernel, bf=bf, k_rows=k_rows),
-        grid_spec=grid_spec,
+        grid=(fp // bf,),
+        in_specs=[
+            pl.BlockSpec((None, 1, bf * k_rows), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=[
+            pl.BlockSpec((bf, w), lambda i: (i, 0)),
+            pl.BlockSpec((bf, 1), lambda i: (i, 0)),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((fp, w), jnp.uint32),
             jax.ShapeDtypeStruct((fp, 1), jnp.int32),
         ],
+        scratch_shapes=[
+            pltpu.VMEM((bf * k_rows, SUBLANES, w), jnp.uint32),
+            pltpu.SemaphoreType.DMA((bf * k_rows,)),
+        ],
         interpret=interpret,
-    )(idx, matrix)
+    )(idx.reshape(fp // bf, 1, bf * k_rows), matrix)
     return and_rows[:, :w32], counts[:, 0]
 
 
@@ -124,19 +140,18 @@ def gather_intersect_xla(matrix: jax.Array, idx: jax.Array, *, w32: int):
 @functools.partial(jax.jit, static_argnames=("n_i", "size"))
 def expand_pairs(and_rows: jax.Array, *, n_i: int, size: int):
     """Device-side frontier expansion: set bits of ``and_rows`` (uint32
-    ``(F, w32)``, little-endian lanes) -> the first ``size`` (row, column)
-    pairs in row-major (= lexicographic) order, as int32 vectors.
+    ``(F, w32)``, little-endian lanes) below column ``n_i`` -> the first
+    ``size`` (row, column) pairs in row-major (= lexicographic) order, as
+    int32 vectors (slots past the last pair hold (0, 0)).
 
     ``size`` is a static page bound: callers bucket it (and slice the
     valid prefix themselves) so the number of retraces stays logarithmic.
-    The dense unpack + nonzero happens on device — the host receives only
+    The selection runs on device without a dense unpack
+    (:func:`repro.kernels.packed.first_set_bits`) — the host receives only
     the compact pair page instead of an ``(F, n_i)`` boolean slab.
     """
-    f, w = and_rows.shape
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    bits = ((and_rows[:, :, None] >> shifts) & jnp.uint32(1)) != 0
-    bits = bits.reshape(f, w * 32)[:, :n_i]
-    (flat,) = jnp.nonzero(bits.reshape(-1), size=size, fill_value=0)
-    rid = (flat // n_i).astype(jnp.int32)
-    cid = (flat % n_i).astype(jnp.int32)
-    return rid, cid
+    _, w = and_rows.shape
+    live = np.clip(n_i - WORD * np.arange(w), 0, WORD).astype(np.uint64)
+    lane_mask = jnp.asarray(((1 << live) - 1).astype(np.uint32))
+    rid, cid, valid = first_set_bits(and_rows & lane_mask, size)
+    return jnp.where(valid, rid, 0), jnp.where(valid, cid, 0)

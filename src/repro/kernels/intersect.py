@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _intersect_kernel(rows_ref, and_ref, cnt_ref, acc_ref, *, k_rows: int):
     w = pl.program_id(1)
@@ -89,7 +87,7 @@ def intersect_pallas(rows: jax.Array, *, bf: int = 128, bw: int = 512,
             jax.ShapeDtypeStruct((fp, 1), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((bf, 1), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rows)

@@ -49,6 +49,44 @@ def popcount(words: jax.Array) -> jax.Array:
     return jax.lax.population_count(words.astype(jnp.uint32)).astype(jnp.int32)
 
 
+def first_set_bits(words: jax.Array, size: int):
+    """The first ``size`` set bits of ``words`` (uint32 ``(F, W)``) in
+    row-major order, as ``(row, col, valid)`` int32/int32/bool ``(size,)``
+    vectors with ``col`` in ``[0, 32 W)``; slots past the last set bit have
+    ``valid`` False (and arbitrary row/col).
+
+    Same answer as ``nonzero(unpack(words), size=size)`` without the dense
+    ``(F, 32 W)`` unpack or a sort: one popcount prefix sum over the
+    words, a binary search per slot for its word, then a 32-lane rank
+    select inside that word.
+    """
+    f, w = words.shape
+    flat = words.reshape(-1)
+    pc = jax.lax.population_count(flat).astype(jnp.int32)
+    incl = jnp.cumsum(pc)
+    slots = jnp.arange(size, dtype=jnp.int32)
+    word = jnp.searchsorted(incl, slots, side="right").astype(jnp.int32)
+    word = jnp.minimum(word, f * w - 1)
+    rank = slots - (incl[word] - pc[word])          # rank inside the word
+    bits = ((flat[word][:, None] >> jnp.arange(WORD, dtype=jnp.uint32))
+            & jnp.uint32(1)).astype(jnp.int32)      # (size, 32)
+    pos = jnp.argmax(jnp.cumsum(bits, axis=1) > rank[:, None],
+                     axis=1).astype(jnp.int32)
+    return word // w, (word % w) * WORD + pos, slots < incl[-1]
+
+
+def pack_host(mask: np.ndarray) -> np.ndarray:
+    """Host (numpy) twin of :func:`pack`: bool ``(..., n)`` -> uint32
+    ``(..., ceil(n/32))``, bit-identical, without touching a device."""
+    mask = np.asarray(mask, dtype=bool)
+    pad = (-mask.shape[-1]) % WORD
+    if pad:
+        mask = np.concatenate(
+            [mask, np.zeros(mask.shape[:-1] + (pad,), dtype=bool)], -1)
+    return np.ascontiguousarray(
+        np.packbits(mask, axis=-1, bitorder="little")).view(np.uint32)
+
+
 def pack_numpy_u64_to_u32(words64: np.ndarray) -> np.ndarray:
     """Reinterpret the host path's packed uint64 words as device uint32 words
     (little-endian layouts are bit-compatible)."""

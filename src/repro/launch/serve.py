@@ -57,6 +57,7 @@ from ..engine import Engine, EngineOptions, QueryParseError, render_trace
 from ..engine.engine import _CounterView
 from ..obs import ServerEvent, Span
 from ..robust import Budget, InjectedFault, TransientError, faults
+from . import compile_cache
 
 _SERVER_COUNTERS = ("served", "redispatched", "rejected", "failed",
                     "host_fallback")
@@ -85,7 +86,7 @@ class Request:
 class QueryServer:
     def __init__(self, graph, *, max_q=8, max_e=16, batch_size=16,
                  capacity=4096, deadline_s=30.0, max_attempts=3,
-                 impl="reference", engine: Optional[Engine] = None,
+                 impl="auto", engine: Optional[Engine] = None,
                  profile: bool = False, budget: Optional[Budget] = None,
                  queue_limit: Optional[int] = None,
                  tenant: Optional[str] = None):
@@ -297,6 +298,7 @@ def main() -> None:
                          "after draining; incident auto-dumps are armed "
                          "to the same path while serving")
     args = ap.parse_args()
+    compile_cache.enable()
 
     graph = random_labeled_graph(args.graph_nodes, avg_degree=3.0,
                                  n_labels=8, seed=args.seed)

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..jaxgm.compat import shard_map
 from ..train import optimizer as opt_mod
 from . import gnn as gnn_mod
 
@@ -94,7 +93,7 @@ def sharded_train_step(cfg: gnn_mod.GNNConfig, mesh: Mesh,
                          is_leaf=lambda x: isinstance(x, tuple))
     opt_spec = {"step": P(), "m": pspec, "v": pspec}
 
-    step = shard_map(
+    step = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(pspec, opt_spec, batch_spec),
         out_specs=(pspec, opt_spec, P()),

@@ -32,7 +32,8 @@ from typing import Callable, Optional
 from . import faults
 from .errors import BreakerOpen, DeviceFailure
 
-__all__ = ["CircuitBreaker", "CLOSED", "HALF_OPEN", "OPEN", "STATE_VALUES"]
+__all__ = ["CircuitBreaker", "CLOSED", "HALF_OPEN", "OPEN", "STATE_VALUES",
+           "governed"]
 
 CLOSED = "closed"
 HALF_OPEN = "half_open"
@@ -184,3 +185,12 @@ class CircuitBreaker:
         raise DeviceFailure(
             f"device dispatch failed after {attempts} attempt(s): "
             f"{type(last).__name__}: {last}") from last
+
+
+def governed(breaker: Optional[CircuitBreaker], fn: Callable[[], object],
+             **kw):
+    """Run one device dispatch ``fn`` through ``breaker.call`` when a
+    breaker governs it, else directly.  Callers build (compile) the
+    executor *before* this point: only the dispatch itself is retried, so
+    a compile error is never mistaken for a transient device failure."""
+    return fn() if breaker is None else breaker.call(fn, **kw)

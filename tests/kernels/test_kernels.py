@@ -31,6 +31,9 @@ def test_pack_unpack_roundtrip(n):
     assert words.dtype == jnp.uint32
     out = packed.unpack(words, n)
     assert np.array_equal(np.asarray(out), np.asarray(mask))
+    # the host packer ships bit-identical words without touching a device
+    assert np.array_equal(packed.pack_host(np.asarray(mask)),
+                          np.asarray(words))
 
 
 def test_popcount():
@@ -57,7 +60,7 @@ def test_bitmm_pallas_vs_ref(m, k, b, threshold):
     dense, words = _rand_packed(rng, m, k)
     x = jnp.asarray(rng.random((k, b)) < 0.3, dtype=jnp.float32)
     want = ref.bitmm_ref(words, x, threshold=threshold)
-    got = bitmm_pallas(words, x, threshold=threshold, bm=128, bk=128,
+    got = bitmm_pallas(words, x, threshold=threshold, bm=128,
                        interpret=True)
     if threshold:
         assert np.array_equal(np.asarray(got) > 0, np.asarray(want))
@@ -65,10 +68,12 @@ def test_bitmm_pallas_vs_ref(m, k, b, threshold):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.parametrize("bm,bk", [(64, 64), (128, 256), (256, 1024)])
+# bk is the whole K or a multiple of 4096 bits (128 word lanes): the block
+# shapes the TPU's (8, 128) rule admits for the packed A operand
+@pytest.mark.parametrize("bm,bk", [(64, 4096), (128, 8192), (256, 4096)])
 def test_bitmm_block_shapes(bm, bk):
     rng = np.random.default_rng(bm * bk)
-    m, k, b = 256, 1024, 8
+    m, k, b = 256, 8192, 8
     dense, words = _rand_packed(rng, m, k, density=0.05)
     x = jnp.asarray(rng.random((k, b)) < 0.5, dtype=jnp.float32)
     want = ref.bitmm_ref(words, x)

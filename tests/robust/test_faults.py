@@ -394,3 +394,71 @@ class TestEngineBudgets:
         # a generous budget changes nothing about the answer
         res2 = _host_engine(g).execute(QUERY, budget=Budget(deadline_s=60.0))
         assert res2.count == res.count and res2.stats.status == "ok"
+
+
+# ==================== no hiding: compile errors surface, faults degrade
+# One case per device path the engine can take: the vmapped device
+# matcher, the frontier slab intersector, and the resident enumerator.
+_DEVICE_PATHS = {
+    "device": dict(force_backend="device"),
+    "frontier-device": dict(force_backend="host",
+                            force_enum="frontier-device"),
+    "frontier-device-resident": dict(force_backend="host",
+                                     force_enum="frontier-device-resident"),
+}
+
+
+def _dense_graph():
+    # uniform topology: frontiers past the padded-dispatch floor, so the
+    # frontier paths really dispatch
+    return random_labeled_graph(1500, avg_degree=3.0, n_labels=4,
+                                kind="uniform", seed=3)
+
+
+def _path_engine(g, path, **kw):
+    return Engine(g, options=EngineOptions(
+        device_min_nodes=0, materialize=False, **_DEVICE_PATHS[path], **kw))
+
+
+@pytest.fixture
+def fresh_slab_executor(monkeypatch):
+    """The slab intersector is a process-wide singleton that caches its
+    compiled executors; start these tests from a cold one."""
+    import importlib
+    monkeypatch.setattr(importlib.import_module("repro.core.mjoin"),
+                        "_DEVICE", None)
+
+
+class TestNoHiding:
+    """A kernel that does not compile is not a transient device failure:
+    it reaches the caller instead of being retried and recomputed on the
+    host.  Faults in the dispatch itself keep the degradation ladder."""
+
+    @pytest.mark.parametrize("path", list(_DEVICE_PATHS))
+    def test_executor_compile_error_reaches_caller(
+            self, path, monkeypatch, fresh_slab_executor):
+        import jax
+
+        def refuse(self, *a, **kw):
+            raise RuntimeError("kernel refused by the compiler")
+
+        g = _dense_graph()
+        eng = _path_engine(g, path)
+        monkeypatch.setattr(jax.stages.Lowered, "compile", refuse)
+        with pytest.raises(RuntimeError, match="refused by the compiler"):
+            eng.execute(QUERY)
+        assert eng.breaker.retries == 0 and eng.breaker.state == CLOSED
+        assert eng.counters["budget_degradations"] == 0
+
+    @pytest.mark.parametrize("path", list(_DEVICE_PATHS))
+    def test_injected_dispatch_fault_degrades_with_exact_count(
+            self, path, fresh_slab_executor):
+        g = _dense_graph()
+        want = _host_engine(g).execute(QUERY).count
+        eng = _path_engine(g, path, breaker=CircuitBreaker(
+            sleep=lambda s: None))
+        with faults.inject(faults.every("device_dispatch", 1)) as plan:
+            res = eng.execute(QUERY)
+            assert plan.calls["device_dispatch"] >= 1
+        assert res.count == want and res.stats.status == "ok"
+        assert res.stats.degradations in (["host"], ["host-intersect"])

@@ -109,3 +109,20 @@ def test_training_end_to_end_with_crash_resume(tmp_path):
     t3.run(30)
     w_resumed = np.asarray(t3.state["params"]["embed"], np.float32)
     np.testing.assert_allclose(w_resumed, w_straight, rtol=1e-5, atol=1e-6)
+
+
+def test_chip_smoke_refuses_without_tpu():
+    """The chip smoke never reports a result from the CPU: without a TPU
+    it exits non-zero before building the graph or running a query."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, str(root / "chip_smoke.py")],
+                         cwd=root, env=env, capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode != 0
+    assert "no TPU found" in out.stderr
+    assert '"ok"' not in out.stdout and "graph" not in out.stdout
