@@ -47,7 +47,8 @@ from ..obs.export import prometheus_text, render_trace
 from ..obs.flight import FlightRecorder
 from ..obs.ledger import get_ledger
 from ..obs.metrics import MetricsRegistry
-from ..obs.trace import NULL_TRACER, Span, Tracer
+from ..obs.process import PROCESS
+from ..obs.trace import NULL_TRACER, PHASE_METRIC, Span, Tracer, phase
 from ..obs.window import WindowedAggregator
 from ..robust import Budget, CircuitBreaker
 from ..robust.errors import (BreakerOpen, DeadlineExceeded, DeviceFailure,
@@ -321,8 +322,9 @@ class _Resident:
     """
 
     def __init__(self, graph: DataGraph, options: EngineOptions,
-                 label_names=None):
+                 label_names=None, metrics: Optional[MetricsRegistry] = None):
         self.ctx = GraphContext(graph)
+        self.metrics = metrics
         self.epoch = next(_RESIDENT_EPOCH)
         self.options = options
         self.vocab = Vocab.for_graph(graph, names=label_names)
@@ -349,9 +351,15 @@ class _Resident:
             self._jgm = JaxGM(self.ctx.graph, max_q=o.max_q, max_e=o.max_e,
                               capacity=o.capacity, exact_sim=o.exact_sim,
                               impl=o.device_impl,
-                              use_transitive_reduction=False)
+                              use_transitive_reduction=False,
+                              metrics=self.metrics)
         return self._jgm
 
+
+# served-path phases of ``execute_many`` (``serve_phase_seconds{phase}``);
+# ``engine.overflow_recompute`` nests in whichever phase met the overflow
+ENGINE_PHASES = ("engine.prepare", "engine.labels", "engine.device_batch",
+                 "engine.finish", "engine.overflow_recompute")
 
 _ENGINE_COUNTERS = (
     "queries", "host_exec", "device_exec", "overflow_fallbacks",
@@ -492,6 +500,11 @@ class Engine:
                           for q in ESTIMATE_QUANTITIES}
         self._c_resident_evicted = self.metrics.counter(
             "cache_resident_evicted_bytes")
+        # served-path phases (profiler annotations + serve_phase_seconds);
+        # GC pauses and programs built come from the process-wide watch,
+        # published into this registry at snapshot time
+        self._phase = {p: h(PHASE_METRIC, phase=p) for p in ENGINE_PHASES}
+        PROCESS.install()
         if graph is not None:
             self.register(graph, label_names=label_names)
 
@@ -517,7 +530,8 @@ class Engine:
         key = id(graph)
         if key not in self._residents:
             self._residents[key] = _Resident(graph, self.options,
-                                             label_names=label_names)
+                                             label_names=label_names,
+                                             metrics=self.metrics)
             # ledger attribution key: every transfer/allocation this graph
             # causes is charged under it.  Callers (e.g. the server's
             # per-tenant rollups) may pre-stamp their own key; the epoch
@@ -879,8 +893,10 @@ class Engine:
                           overflowed=True, rig_nodes=stats.rig_nodes)
             # the host re-run records the real rig/enumerate/materialize
             # spans for this query
-            m = self._run_host(res, qr, entry, stats, materialize,
-                               trace=trace, budget=budget)
+            with phase("engine.overflow_recompute",
+                       self._phase["engine.overflow_recompute"]):
+                m = self._run_host(res, qr, entry, stats, materialize,
+                                   trace=trace, budget=budget)
             stats.backend = DEVICE          # device ran; host completed
             stats.overflow_fallback = True
             self.counters["overflow_fallbacks"] += 1
@@ -1140,34 +1156,40 @@ class Engine:
            that micro-batches their per-level ``(F, K, W)`` constraint
            gathers into a single ``(ΣF, K, W)`` slab per round; remaining
            host queries run sequentially.
+
+        The served path's phases (``engine.prepare``, ``engine.labels``,
+        ``engine.device_batch``, ``engine.finish``) are profiler
+        annotations timed into ``serve_phase_seconds``.
         """
-        items: List[Tuple[QueryLike, Optional[DataGraph]]] = []
-        for item in queries:
-            if isinstance(item, tuple):
-                q, g = item
-                items.append((q, g))
-            else:
-                items.append((item, graph))
-        # group indices per resident graph (registration happens here, so
-        # group order follows first appearance in the batch)
-        groups: "OrderedDict[int, Tuple[_Resident, List[int]]]" = \
-            OrderedDict()
-        residents: List[_Resident] = []
-        for i, (_, g) in enumerate(items):
-            res = self._resident(g)
-            groups.setdefault(id(res), (res, []))[1].append(i)
-            residents.append(res)
-        # parse/plan the whole batch first (admission control); each
-        # request gets its own armed copy of the budget template — one slow
-        # request blowing its deadline must not cancel its batch-mates
-        prepared = []
-        for i, (q, _) in enumerate(items):
-            stats = EngineStats()
-            trace = Tracer("query") if profile else NULL_TRACER
-            qr, key, entry = self._prepare(q, residents[i], stats,
-                                           trace=trace)
-            prepared.append((qr, key, entry, stats, trace,
-                             self._arm_budget(budget)))
+        with phase("engine.prepare", self._phase["engine.prepare"]):
+            items: List[Tuple[QueryLike, Optional[DataGraph]]] = []
+            for item in queries:
+                if isinstance(item, tuple):
+                    q, g = item
+                    items.append((q, g))
+                else:
+                    items.append((item, graph))
+            # group indices per resident graph (registration happens here,
+            # so group order follows first appearance in the batch)
+            groups: "OrderedDict[int, Tuple[_Resident, List[int]]]" = \
+                OrderedDict()
+            residents: List[_Resident] = []
+            for i, (_, g) in enumerate(items):
+                res = self._resident(g)
+                groups.setdefault(id(res), (res, []))[1].append(i)
+                residents.append(res)
+            # parse/plan the whole batch first (admission control); each
+            # request gets its own armed copy of the budget template — one
+            # slow request blowing its deadline must not cancel its
+            # batch-mates
+            prepared = []
+            for i, (q, _) in enumerate(items):
+                stats = EngineStats()
+                trace = Tracer("query") if profile else NULL_TRACER
+                qr, key, entry = self._prepare(q, residents[i], stats,
+                                               trace=trace)
+                prepared.append((qr, key, entry, stats, trace,
+                                 self._arm_budget(budget)))
         results: List[Optional[EngineResult]] = [None] * len(items)
         for res, idxs in groups.values():
             self._execute_group(res, idxs, prepared, results)
@@ -1182,23 +1204,27 @@ class Engine:
 
     def _execute_group(self, res: _Resident, idxs: List[int],
                        prepared, results) -> None:
-        """Run one resident graph's share of an ``execute_many`` batch."""
-        t0 = time.perf_counter()
-        label_hit = res.ctx.ensure_labels()
-        build_s = time.perf_counter() - t0
-        if not label_hit:
-            self.counters["label_builds"] += 1
-        for j, i in enumerate(idxs):
-            # resident for every query after the first in this group
-            hit = label_hit or j > 0
-            prepared[i][3].label_cache_hit = hit
-            tr = prepared[i][4]
-            if tr.enabled:
-                sp = tr.add("labels", duration_s=0.0 if hit else build_s,
-                            cached=hit)
-                if not hit:
-                    for name, dur in res.ctx.label_phases:
-                        sp.children.append(Span(name, duration_s=dur))
+        """Run one resident graph's share of an ``execute_many`` batch:
+        labels, the vmapped device batch, the host lanes, then the
+        ``engine.finish`` phase (device results, duplicates, telemetry)."""
+        ph = self._phase
+        with phase("engine.labels", ph["engine.labels"]):
+            t0 = time.perf_counter()
+            label_hit = res.ctx.ensure_labels()
+            build_s = time.perf_counter() - t0
+            if not label_hit:
+                self.counters["label_builds"] += 1
+            for j, i in enumerate(idxs):
+                # resident for every query after the first in this group
+                hit = label_hit or j > 0
+                prepared[i][3].label_cache_hit = hit
+                tr = prepared[i][4]
+                if tr.enabled:
+                    sp = tr.add("labels", duration_s=0.0 if hit else build_s,
+                                cached=hit)
+                    if not hit:
+                        for name, dur in res.ctx.label_phases:
+                            sp.children.append(Span(name, duration_s=dur))
 
         # dedup by canonical key: the first occurrence executes, the rest
         # are answered from its result (all batch members share the same
@@ -1218,41 +1244,29 @@ class Engine:
         device_idx = [i for i in reps if lane[i] == "device"]
         fd_idx = [i for i in reps if lane[i] == "frontier-device"]
 
-        jgm = res.jgm() if device_idx else None
-        if jgm is not None and len(device_idx) >= 2:
-            t0 = time.perf_counter()
-            run = jgm.prepare_batch([prepared[i][0] for i in device_idx])
-            try:
-                batch = self.breaker.call(run)
-            except (DeviceFailure, BreakerOpen):
-                # whole-batch device loss: every member degrades to the
-                # host singles lane below (recompute, not repair)
-                for i in device_idx:
-                    stats = prepared[i][3]
-                    if "host" not in stats.degradations:
-                        stats.degradations.append("host")
-                        self.counters["budget_degradations"] += 1
-                batch = None
-                device_idx = []
-            if batch is not None:
+        # the vmapped device batch; its results are finished below, after
+        # the host lanes, inside ``engine.finish``
+        batched: List[int] = []
+        batch, dt = None, 0.0
+        if len(device_idx) >= 2:
+            with phase("engine.device_batch", ph["engine.device_batch"]):
+                jgm = res.jgm()
+                t0 = time.perf_counter()
+                run = jgm.prepare_batch([prepared[i][0] for i in device_idx])
+                try:
+                    batch = self.breaker.call(run)
+                except (DeviceFailure, BreakerOpen):
+                    # whole-batch device loss: every member degrades to the
+                    # host singles lane below (recompute, not repair)
+                    for i in device_idx:
+                        stats = prepared[i][3]
+                        if "host" not in stats.degradations:
+                            stats.degradations.append("host")
+                            self.counters["budget_degradations"] += 1
                 dt = time.perf_counter() - t0
-                for i, dev in zip(device_idx, batch):
-                    qr, key, entry, stats, tr, b = prepared[i]
-                    t1 = time.perf_counter()
-                    count, _ = self._post_device(
-                        res, qr, entry, stats, dev,
-                        materialize=False, trace=tr,
-                        dispatch_s=dt / len(device_idx), budget=b)
-                    # this query's share of the batched dispatch, plus any
-                    # host overflow-fallback time it caused individually
-                    stats.exec_s = (dt / len(device_idx)
-                                    + time.perf_counter() - t1)
-                    self._finish(stats, count)
-                    results[i] = EngineResult(
-                        count=count, tuples=None, query=qr, plan=entry.plan,
-                        stats=stats, key=key,
-                        trace=self._finish_trace(tr, key, stats, count))
-                device_idx = []
+            if batch is not None:
+                batched = device_idx
+            device_idx = []
 
         if len(fd_idx) >= 2:
             # micro-batched frontier lane: one fused (ΣF, K, W) slab per
@@ -1269,13 +1283,13 @@ class Engine:
                 [prepared[i][0] for i in fd_idx], gm_opts,
                 intersector=device_intersector(),
                 traces=[prepared[i][4] for i in fd_idx])
-            dt = time.perf_counter() - t0
+            fd_dt = time.perf_counter() - t0
             self.counters["frontier_batches"] += 1
             self.counters["frontier_batch_dispatches"] += dispatches
             for i, m in zip(fd_idx, ms):
                 qr, key, entry, stats, tr, b = prepared[i]
                 self._observe_host(entry, stats, m)
-                stats.exec_s = dt / len(fd_idx)   # share of the fused run
+                stats.exec_s = fd_dt / len(fd_idx)   # share of the fused run
                 self._finish(stats, m.count)
                 if tr.enabled:
                     # the rig span was recorded live by prepare_rig; the
@@ -1292,16 +1306,18 @@ class Engine:
             fd_idx = []
 
         for i in reps:
-            if results[i] is not None:
+            if results[i] is not None or i in batched:
                 continue
             qr, key, entry, stats, tr, b = prepared[i]
             t0 = time.perf_counter()
             try:
-                if i in device_idx and jgm is not None:
+                if i in device_idx:
                     # singleton device query: non-batched dispatch
-                    run = jgm.prepare(qr, materialize=False)
                     try:
-                        dev = self.breaker.call(run, budget=b)
+                        with phase("engine.device_batch",
+                                   ph["engine.device_batch"]):
+                            run = res.jgm().prepare(qr, materialize=False)
+                            dev = self.breaker.call(run, budget=b)
                         count, _ = self._post_device(
                             res, qr, entry, stats, dev, materialize=False,
                             trace=tr, dispatch_s=time.perf_counter() - t0,
@@ -1330,6 +1346,32 @@ class Engine:
                     self.counters["deadline_exceeded"] += 1
                 count = 0
             stats.exec_s = time.perf_counter() - t0
+            self._finish(stats, count)
+            results[i] = EngineResult(
+                count=count, tuples=None, query=qr, plan=entry.plan,
+                stats=stats, key=key,
+                trace=self._finish_trace(tr, key, stats, count))
+
+        with phase("engine.finish", ph["engine.finish"]):
+            self._finish_group(res, idxs, prepared, results, batched, batch,
+                               dt, dups)
+
+    def _finish_group(self, res: _Resident, idxs: List[int], prepared,
+                      results, batched: List[int], batch, dt: float,
+                      dups: Dict[int, List[int]]) -> None:
+        """The ``engine.finish`` phase: the device batch's results (with
+        any overflow recompute), the duplicates' answers, and one
+        telemetry event per batch member."""
+        for i, dev in zip(batched, batch or ()):
+            qr, key, entry, stats, tr, b = prepared[i]
+            t1 = time.perf_counter()
+            count, _ = self._post_device(
+                res, qr, entry, stats, dev,
+                materialize=False, trace=tr,
+                dispatch_s=dt / len(batched), budget=b)
+            # this query's share of the batched dispatch, plus any host
+            # overflow-fallback time it caused individually
+            stats.exec_s = dt / len(batched) + time.perf_counter() - t1
             self._finish(stats, count)
             results[i] = EngineResult(
                 count=count, tuples=None, query=qr, plan=entry.plan,
@@ -1387,11 +1429,13 @@ class Engine:
         ledger is published into the registry first, so ``ledger_*`` series
         reflect this instant."""
         self.ledger.publish(self.metrics)
+        PROCESS.publish(self.metrics)
         return self.metrics.snapshot(prefix)
 
     def metrics_text(self) -> str:
         """Prometheus-style text exposition of the engine registry."""
         self.ledger.publish(self.metrics)
+        PROCESS.publish(self.metrics)
         return prometheus_text(self.metrics)
 
     @staticmethod

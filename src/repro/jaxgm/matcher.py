@@ -8,7 +8,6 @@ argument (shared).
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from functools import partial
 from typing import Callable, List, Optional, Sequence
@@ -19,11 +18,18 @@ import numpy as np
 
 from ..core.graph import DataGraph
 from ..core.query import PatternQuery
+from ..obs.metrics import MetricsRegistry
+from ..obs.process import PROCESS
+from ..obs.trace import PHASE_METRIC, phase
 from . import device_graph
 from .device_graph import DeviceGraph
 from .encoding import QueryTensor, encode_batch, encode_query, jo_order
 from .enumerate import MJoinCount, decode_tuples, mjoin_count
 from .simulation import double_simulation, fb_sizes, rig_edge_counts
+
+
+JAXGM_PHASES = ("jaxgm.encode", "jaxgm.compile", "jaxgm.dispatch",
+                "jaxgm.readback")
 
 
 @dataclass
@@ -52,7 +58,11 @@ class JaxGM:
     The pipeline is compiled **ahead of time** once per shape — single
     query with or without materialization, or a vmapped batch of a given
     size — with the wall time kept in ``compile_s`` apart from
-    ``kernel_s``, the fenced device time of the dispatches.
+    ``kernel_s``, the fenced device time of the dispatches.  Each step is
+    a served-path phase (``jaxgm.encode``, ``jaxgm.compile``,
+    ``jaxgm.dispatch``, ``jaxgm.readback``): a profiler annotation timed
+    into ``serve_phase_seconds`` of ``metrics`` (the engine's registry, or
+    a private one).
     :meth:`prepare` / :meth:`prepare_batch` compile and return the
     dispatch as a zero-argument callable, so callers can govern the
     dispatch alone (a compile error raises from ``prepare*``, never from
@@ -63,7 +73,8 @@ class JaxGM:
                  block: int = 512, capacity: int = 4096, n_passes: int = 4,
                  exact_sim: bool = False, impl: str = "auto",
                  closure_on_device: bool = False,
-                 use_transitive_reduction: bool = True):
+                 use_transitive_reduction: bool = True,
+                 metrics: Optional[MetricsRegistry] = None):
         self.graph = graph
         self.max_q, self.max_e = max_q, max_e
         self.capacity, self.n_passes = capacity, n_passes
@@ -76,6 +87,10 @@ class JaxGM:
         self.compile_s = 0.0      # one-time AOT compile time per shape
         self.kernel_s = 0.0       # fenced per-dispatch device time
         self._compiled = {}
+        reg = metrics if metrics is not None else MetricsRegistry()
+        self._phase = {p: reg.histogram(PHASE_METRIC, phase=p)
+                       for p in JAXGM_PHASES}
+        PROCESS.install()
 
     def _prep(self, q: PatternQuery) -> tuple:
         if self.use_tr:
@@ -93,34 +108,36 @@ class JaxGM:
                              materialize=materialize)
             f = single if batch is None else jax.vmap(single,
                                                       in_axes=(None, 0))
-            t0 = time.perf_counter()
-            fn = jax.jit(f).lower(self.dg, qt).compile()
-            self.compile_s += time.perf_counter() - t0
+            with phase("jaxgm.compile", self._phase["jaxgm.compile"]) as ph:
+                fn = jax.jit(f).lower(self.dg, qt).compile()
+            self.compile_s += ph.duration_s
             self._compiled[key] = fn
         return fn
 
     def _dispatch(self, fn, qt: QueryTensor):
-        t0 = time.perf_counter()
-        out = fn(self.dg, qt)
-        jax.block_until_ready(out)
-        self.kernel_s += time.perf_counter() - t0
+        with phase("jaxgm.dispatch", self._phase["jaxgm.dispatch"]) as ph:
+            out = fn(self.dg, qt)
+            jax.block_until_ready(out)
+        self.kernel_s += ph.duration_s
         self.calls += 1
         return out
 
     def prepare(self, q: PatternQuery, materialize: bool = False
                 ) -> Callable[[], JaxMatchResult]:
-        q, qt = self._prep(q)
+        with phase("jaxgm.encode", self._phase["jaxgm.encode"]):
+            q, qt = self._prep(q)
         fn = self._executor(qt, None, materialize)
 
         def run() -> JaxMatchResult:
             res, sizes, order = self._dispatch(fn, qt)
-            tuples = None
-            if materialize:
-                tuples = decode_tuples(res, order, q.n)
-            return JaxMatchResult(count=int(res.count),
-                                  overflowed=bool(res.overflowed),
-                                  fb_sizes=np.asarray(sizes)[:q.n],
-                                  tuples=tuples)
+            with phase("jaxgm.readback", self._phase["jaxgm.readback"]):
+                tuples = None
+                if materialize:
+                    tuples = decode_tuples(res, order, q.n)
+                return JaxMatchResult(count=int(res.count),
+                                      overflowed=bool(res.overflowed),
+                                      fb_sizes=np.asarray(sizes)[:q.n],
+                                      tuples=tuples)
         return run
 
     def match(self, q: PatternQuery,
@@ -129,19 +146,22 @@ class JaxGM:
 
     def prepare_batch(self, queries: Sequence[PatternQuery]
                       ) -> Callable[[], List[JaxMatchResult]]:
-        prepped = [self._prep(q) for q in queries]
-        qts = jax.tree.map(lambda *xs: jnp.stack(xs),
-                           *[qt for _, qt in prepped])
+        with phase("jaxgm.encode", self._phase["jaxgm.encode"]):
+            prepped = [self._prep(q) for q in queries]
+            qts = jax.tree.map(lambda *xs: jnp.stack(xs),
+                               *[qt for _, qt in prepped])
         fn = self._executor(qts, len(prepped), False)
 
         def run() -> List[JaxMatchResult]:
             res, sizes, _ = self._dispatch(fn, qts)
-            count, over = np.asarray(res.count), np.asarray(res.overflowed)
-            sizes = np.asarray(sizes)
-            return [JaxMatchResult(count=int(count[i]),
-                                   overflowed=bool(over[i]),
-                                   fb_sizes=sizes[i][:q.n])
-                    for i, (q, _) in enumerate(prepped)]
+            with phase("jaxgm.readback", self._phase["jaxgm.readback"]):
+                count = np.asarray(res.count)
+                over = np.asarray(res.overflowed)
+                sizes = np.asarray(sizes)
+                return [JaxMatchResult(count=int(count[i]),
+                                       overflowed=bool(over[i]),
+                                       fb_sizes=sizes[i][:q.n])
+                        for i, (q, _) in enumerate(prepped)]
         return run
 
     def match_batch(self, queries: Sequence[PatternQuery]

@@ -35,7 +35,14 @@ Production behaviours:
   bounded flight recorder, ``--stats-interval N`` prints a windowed
   QPS/p50/p95/p99/error-rate line every N seconds, and ``--flight-dump
   PATH`` writes the JSONL dump at exit (incident auto-dumps — breaker
-  open, deadline-rate spike — are armed to the same path).
+  open, deadline-rate spike — are armed to the same path);
+* **step phases** — every step is a tree of profiler annotations timed
+  into ``serve_phase_seconds{phase}``: ``serve.step`` over
+  ``serve.pending``, the engine's ``engine.*`` and ``serve.collect``;
+  ``phase="host"`` is each step's wall minus its fenced device dispatch.
+  A step over 4x the running median of the last 64 (once 8 were seen)
+  counts in ``server_slow_steps`` and leaves a ``slow_step`` flight event
+  with its phase seconds, GC seconds, programs built and recomputes.
 
 Usage:
   python -m repro.launch.serve --n-queries 64 --graph-nodes 2000 \
@@ -46,7 +53,9 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import statistics
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Union
 
@@ -56,11 +65,22 @@ from ..data.queries import random_query_from_graph
 from ..engine import Engine, EngineOptions, QueryParseError, render_trace
 from ..engine.engine import _CounterView
 from ..obs import ServerEvent, Span
+from ..obs.process import PROCESS
+from ..obs.trace import PHASE_METRIC, phase
 from ..robust import Budget, InjectedFault, TransientError, faults
 from . import compile_cache
 
 _SERVER_COUNTERS = ("served", "redispatched", "rejected", "failed",
-                    "host_fallback")
+                    "slow_steps")
+
+SERVER_PHASES = ("serve.step", "serve.pending", "serve.collect",
+                 "serve.submit", "host")
+
+# slow step: over SLOW_FACTOR x the median of the last SLOW_WINDOW steps,
+# judged once SLOW_MIN_STEPS have been seen
+SLOW_FACTOR = 4.0
+SLOW_WINDOW = 64
+SLOW_MIN_STEPS = 8
 
 # terminal request states (everything else re-enters the pending pool)
 _TERMINAL = ("done", "failed")
@@ -122,6 +142,10 @@ class QueryServer:
         # terminal give-ups) land in the engine's flight recorder next to
         # the per-request query events, so one dump tells the whole story
         self.flight = self.engine.flight
+        reg = self.engine.metrics
+        self._phase = {p: reg.histogram(PHASE_METRIC, phase=p)
+                       for p in SERVER_PHASES}
+        self._step_walls: "deque[float]" = deque(maxlen=SLOW_WINDOW)
 
     def metrics_text(self) -> str:
         """Prometheus-style dump of engine + cache + server series."""
@@ -151,6 +175,10 @@ class QueryServer:
         query text is rejected with the caret-annotated parse error, and a
         full queue (``queue_limit`` pending requests) rejects rather than
         buffering unboundedly — both recorded in ``self.rejected[rid]``."""
+        with phase("serve.submit", self._phase["serve.submit"]):
+            return self._submit(rid, query)
+
+    def _submit(self, rid: int, query: Union[str, PatternQuery]) -> bool:
         if (self.queue_limit is not None
                 and len(self._pending()) >= self.queue_limit):
             self.rejected[rid] = (f"queue full ({self.queue_limit} pending "
@@ -191,16 +219,62 @@ class QueryServer:
             out.append(r)
         return out
 
+    def _phase_seconds(self) -> Dict[str, float]:
+        """Cumulative seconds of every ``serve_phase_seconds`` series."""
+        return {dict(m.labels)["phase"]: m.total
+                for m in self.engine.metrics if m.name == PHASE_METRIC}
+
     def step(self, fail: bool = False) -> int:
         """Serve one micro-batch; ``fail=True`` (or a ``journal_dispatch``
         injected fault) simulates a worker dying mid-batch — the requests
         stay journaled, the attempt is spent, and the next step
-        re-dispatches them."""
-        batch = self._pending()[:self.batch_size]
+        re-dispatches them.  Records the step's phases, its host time and
+        whether it was a slow step."""
+        before = self._phase_seconds()
+        gc0, builds0 = PROCESS.gc_seconds(), PROCESS.compiles
+        over0 = self.engine.counters["overflow_fallbacks"]
+        with phase("serve.step", self._phase["serve.step"]) as sp:
+            served = self._step(fail)
+        wall = sp.duration_s
+        phases = {k: v - before.get(k, 0.0)
+                  for k, v in self._phase_seconds().items()
+                  if k not in ("serve.step", "host")
+                  and v != before.get(k, 0.0)}
+        self._phase["host"].observe(wall - phases.get("jaxgm.dispatch", 0.0))
+        walls = self._step_walls
+        if len(walls) >= SLOW_MIN_STEPS:
+            median = statistics.median(walls)
+            if wall > SLOW_FACTOR * median:
+                self._slow_step(wall, median, phases,
+                                PROCESS.gc_seconds() - gc0,
+                                PROCESS.compiles - builds0,
+                                self.engine.counters["overflow_fallbacks"]
+                                - over0)
+        walls.append(wall)
+        return served
+
+    def _slow_step(self, wall: float, median: float,
+                   phases: Dict[str, float], gc_s: float, builds: int,
+                   recomputes: int) -> None:
+        self.stats["slow_steps"] += 1
+        if not self.engine.telemetry:
+            return
+        longest = max(phases, key=phases.get) if phases else ""
+        self.flight.record(ServerEvent(
+            action="slow_step",
+            detail=(f"step {wall * 1e3:.1f} ms, median {median * 1e3:.1f} "
+                    f"ms; longest phase {longest}"),
+            data={"wall_s": wall, "median_s": median, "phases": phases,
+                  "longest_phase": longest, "gc_s": gc_s,
+                  "compiles": builds, "overflow_recomputes": recomputes}))
+
+    def _step(self, fail: bool) -> int:
+        with phase("serve.pending", self._phase["serve.pending"]):
+            batch = self._pending()[:self.batch_size]
+            for r in batch:
+                r.attempts += 1
         if not batch:
             return 0
-        for r in batch:
-            r.attempts += 1
         if fail:                              # worker loss: nothing returns
             self.stats["redispatched"] += len(batch)
             for r in batch:
@@ -240,6 +314,10 @@ class QueryServer:
                 self._record_server_event("redispatch", r,
                                           detail="straggler batch split")
             return 0
+        with phase("serve.collect", self._phase["serve.collect"]):
+            return self._collect(batch, results)
+
+    def _collect(self, batch: List[Request], results) -> int:
         served = 0
         for r, res in zip(batch, results):
             st = res.stats.status
@@ -258,8 +336,6 @@ class QueryServer:
             r.backend = res.stats.backend
             r.outcome = st
             r.trace = res.trace
-            if res.stats.overflow_fallback:
-                self.stats["host_fallback"] += 1
             r.done = True
             r.status = "done"
             self.stats["served"] += 1

@@ -10,7 +10,8 @@ engine emits one for every request on *all three* execution modes
 :class:`BreakerEvent` records circuit-breaker state transitions (the
 recorder auto-dumps when one lands on ``open``), and :class:`ServerEvent`
 records ``QueryServer`` lifecycle actions that never reach the engine —
-admission rejections, journal re-dispatches, terminal give-ups.
+admission rejections, journal re-dispatches, terminal give-ups, and slow
+steps with their phase durations.
 """
 
 from __future__ import annotations
@@ -22,7 +23,7 @@ from typing import Any, ClassVar, Dict, List
 __all__ = ["EVENT_SCHEMA_VERSION", "QueryEvent", "BreakerEvent",
            "ServerEvent", "event_dict"]
 
-EVENT_SCHEMA_VERSION = 2    # v2: ledger byte tags on QueryEvent
+EVENT_SCHEMA_VERSION = 3    # v3: ServerEvent.data (slow-step evidence)
 
 
 def event_dict(event: Any) -> Dict[str, Any]:
@@ -138,13 +139,16 @@ class ServerEvent:
 
     kind: ClassVar[str] = "server"
 
-    action: str = ""               # reject | redispatch | failed
+    action: str = ""        # reject | redispatch | failed | slow_step
     rid: int = -1
     attempts: int = 0
     detail: str = ""
+    # slow_step: the step's wall and median, its phase seconds, GC seconds,
+    # programs built and overflow recomputes
+    data: Dict[str, Any] = field(default_factory=dict)
     ts: float = field(default_factory=time.time)
 
     def to_dict(self) -> Dict[str, Any]:
         return {"kind": self.kind, "ts": self.ts, "action": self.action,
                 "rid": self.rid, "attempts": self.attempts,
-                "detail": self.detail}
+                "detail": self.detail, "data": dict(self.data)}

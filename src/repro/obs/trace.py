@@ -20,21 +20,92 @@ Two tracer flavours share one calling convention:
   dicts, no timestamps are ever allocated, so un-profiled queries pay a
   few no-op method calls and nothing else.  ``Tracer.enabled`` lets hot
   loops skip even attribute construction (``if trace.enabled: ...``).
+
+Both the recorded spans and the always-on :class:`phase` blocks open a
+``jax.profiler.TraceAnnotation`` of their name, so while a JAX profiler
+trace runs they appear on the host timeline beside the device's
+operations.  :class:`phase` is the served path's primitive: one block,
+timed on ``perf_counter`` into a histogram (``serve_phase_seconds``
+with ``phase=<name>``), whether or not anything is profiled.
 """
 
 from __future__ import annotations
 
+import sys
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
-__all__ = ["Span", "Tracer", "NullTracer", "NULL_TRACER"]
+__all__ = ["Span", "Tracer", "NullTracer", "NULL_TRACER", "phase",
+           "PHASE_METRIC"]
+
+#: Histogram family the served path's :class:`phase` blocks feed.
+PHASE_METRIC = "serve_phase_seconds"
+
+_ANNOTATION = None      # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+class _NoAnnotation:
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_ANNOTATION = _NoAnnotation()
+
+
+def _annotation(name: str):
+    """A profiler annotation named ``name``; a shared no-op until some
+    module has imported JAX (no profiler can run before that, and a
+    host-only process never pays the import)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return _NO_ANNOTATION
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name)
+
+
+class phase:
+    """Time one named block of the served path::
+
+        with phase("engine.prepare", hist):
+            ...
+
+    Opens a profiler annotation of ``name``, times the block on
+    ``perf_counter`` and observes the seconds into ``hist`` (the
+    ``serve_phase_seconds{phase=<name>}`` series of a registry).  The
+    block's time is left in ``duration_s``."""
+
+    __slots__ = ("name", "hist", "t0", "duration_s", "_ann")
+
+    def __init__(self, name: str, hist):
+        self.name = name
+        self.hist = hist
+        self.duration_s = 0.0
+
+    def __enter__(self) -> "phase":
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type=None, exc=None, tb=None) -> bool:
+        self.duration_s = time.perf_counter() - self.t0
+        self._ann.__exit__(exc_type, exc, tb)
+        self.hist.observe(self.duration_s)
+        return False
 
 
 class Span:
     """One timed, named node of a trace tree."""
 
     __slots__ = ("name", "t0", "t1", "attrs", "children", "_tracer",
-                 "_duration")
+                 "_duration", "_ann")
 
     def __init__(self, name: str, tracer: Optional["Tracer"] = None,
                  t0: Optional[float] = None, t1: Optional[float] = None,
@@ -47,9 +118,12 @@ class Span:
         self._duration = duration_s
         self.attrs: Dict[str, Any] = attrs or {}
         self.children: List["Span"] = []
+        self._ann = None
 
     # ------------------------------------------------------- context manager
     def __enter__(self) -> "Span":
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         if self._tracer is not None:
             self._tracer._push(self)
@@ -57,6 +131,9 @@ class Span:
 
     def __exit__(self, exc_type=None, exc=None, tb=None) -> bool:
         self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         if exc_type is not None:
             # a span terminated by an exception carries its cause: the
             # class name plus — for the typed QueryError taxonomy — the
