@@ -5,11 +5,17 @@ backtracking enumeration becomes a *level-synchronous frontier expansion*:
 a fixed-capacity table of partial assignments is extended one query node at
 a time (following the search order), where each extension is the same
 multiway packed-bitset intersection as the paper's — ``cos(q_i)`` AND one
-RIG adjacency row per bound neighbour — realized as flat gathers over the
-stacked packed matrices plus word-wise ANDs (the ``intersect`` kernel's
+RIG adjacency row per bound neighbour — realized as row gathers from the
+four packed matrices plus word-wise ANDs (the ``intersect`` kernel's
 semantics).  Intermediate results remain intersections (never joins), so
 the "no exploding intermediates" property carries over; a capacity overflow
 is *detected and reported* rather than silently truncated.
+
+An edge binds exactly one level, the later of its endpoints' positions in
+the search order, so the edges are sorted by that level once and each
+level's constraint loop runs over its own segment only: a query costs one
+row gather per edge in all, not ``max_q × max_e`` (a vmapped batch, per
+level the most of any of its queries).
 """
 
 from __future__ import annotations
@@ -30,6 +36,7 @@ class MJoinCount(NamedTuple):
     overflowed: jax.Array     # bool
     frontier: jax.Array       # (capacity, max_q) int32 — last-level partials
     alive: jax.Array          # (capacity,) bool
+    level_edges: jax.Array    # (max_q,) int32 — edges that bind each level
 
 
 def _inverse_order(order: jax.Array, max_q: int) -> jax.Array:
@@ -42,6 +49,33 @@ def _inverse_order(order: jax.Array, max_q: int) -> jax.Array:
     return inv.at[safe].min(updates)
 
 
+def _edges_by_level(qt: QueryTensor, inv: jax.Array):
+    """The query edges sorted (stably) by the level they bind.
+
+    Edge ``e`` binds level ``max(inv[src], inv[dst])``: the later of its
+    endpoints fixes the new node against the earlier, bound one.  Padding
+    edges and edges whose endpoints share a position bind no level.
+    Returns ``(start, k, jpos, mat_id)``: level ``i``'s edges are
+    ``[start[i], start[i] + k[i])`` of the sorted ``jpos`` (the bound
+    endpoint's position) and ``mat_id`` (operand matrix: ``2 *
+    is_backward + (kind == DESC)``).
+    """
+    max_q = qt.max_q
+    kind = qt.edge_kind
+    psrc = jnp.take(inv, jnp.clip(qt.edge_src, 0, max_q - 1))
+    pdst = jnp.take(inv, jnp.clip(qt.edge_dst, 0, max_q - 1))
+    binds = (kind >= 0) & (psrc != pdst)
+    level = jnp.where(binds, jnp.maximum(psrc, pdst), max_q)
+    perm = jnp.argsort(level, stable=True)
+    k = (level[None, :] == jnp.arange(max_q)[:, None]).sum(
+        axis=1, dtype=jnp.int32)
+    start = jnp.cumsum(k) - k
+    jpos = jnp.clip(jnp.minimum(psrc, pdst), 0, max_q - 1)
+    # src bound first -> its forward row; dst bound first -> backward row
+    mat_id = jnp.where(psrc < pdst, 0, 2) + jnp.clip(kind, 0, 1)
+    return start, k, jnp.take(jpos, perm), jnp.take(mat_id, perm)
+
+
 @partial(jax.jit, static_argnames=("capacity", "materialize"))
 def mjoin_count(dg: DeviceGraph, qt: QueryTensor, fb: jax.Array,
                 order: jax.Array, *, capacity: int = 4096,
@@ -50,6 +84,10 @@ def mjoin_count(dg: DeviceGraph, qt: QueryTensor, fb: jax.Array,
 
     fb: (max_q, n_pad) bool — the double-simulation candidate sets;
     order: (max_q,) int32 search order (PAD beyond n_nodes).
+
+    Level ``i`` ANDs ``cos(order[i])`` with one adjacency row per edge
+    that binds it, looping over those edges only (``level_edges[i]``
+    trips; under ``vmap`` the batch's largest).
     """
     np_, max_q, max_e = dg.n_pad, qt.max_q, qt.max_e
     w = dg.n_words
@@ -58,6 +96,7 @@ def mjoin_count(dg: DeviceGraph, qt: QueryTensor, fb: jax.Array,
     mats = (dg.adj, dg.reach, dg.adj_t, dg.reach_t)
     fb_words = packed.pack(fb)                       # (max_q, W)
     inv = _inverse_order(order, max_q)
+    start, k, jpos, mat_id = _edges_by_level(qt, inv)
 
     assign = jnp.full((capacity, max_q), PAD, jnp.int32)
     alive = jnp.zeros(capacity, bool).at[0].set(True)
@@ -70,26 +109,20 @@ def mjoin_count(dg: DeviceGraph, qt: QueryTensor, fb: jax.Array,
         is_last = i == qt.n_nodes - 1
 
         def constrain(e, cand):
-            """AND in edge e's adjacency row when it binds level i."""
-            src, dst, kind = qt.edge_src[e], qt.edge_dst[e], qt.edge_kind[e]
-            valid = kind >= 0
-            psrc = jnp.take(inv, jnp.clip(src, 0, max_q - 1))
-            pdst = jnp.take(inv, jnp.clip(dst, 0, max_q - 1))
-            f_app = valid & (pdst == i) & (psrc < i)   # src bound -> fwd row
-            b_app = valid & (psrc == i) & (pdst < i)   # dst bound -> bwd row
-            jpos = jnp.where(f_app, psrc, pdst)
-            mat_id = jnp.where(f_app, 0, 2) + jnp.clip(kind, 0, 1)
-            t_col = jnp.take(assign, jnp.clip(jpos, 0, max_q - 1), axis=1)
+            """AND in sorted edge e's row of its bound endpoint."""
+            e = jnp.minimum(e, max_e - 1)    # a finished vmap lane's index
+            t_col = jnp.take(assign, jpos[e], axis=1)
             row_idx = jnp.clip(t_col, 0, np_ - 1)
             rows = jnp.take(mats[0], row_idx, axis=0)            # (F, W)
             for m in range(1, 4):
-                rows = jnp.where(mat_id == m,
+                rows = jnp.where(mat_id[e] == m,
                                  jnp.take(mats[m], row_idx, axis=0), rows)
-            return jnp.where(f_app | b_app, cand & rows, cand)
+            return cand & rows
 
-        # a rolled loop: one edge's gathers live at a time
+        # a rolled loop over the level's own edges: one edge's gathers
+        # live at a time
         cand = jax.lax.fori_loop(
-            0, max_e, constrain,
+            start[i], start[i] + k[i], constrain,
             jnp.broadcast_to(jnp.take(fb_words, qi, axis=0)[None, :],
                              (capacity, w)))
 
@@ -110,7 +143,7 @@ def mjoin_count(dg: DeviceGraph, qt: QueryTensor, fb: jax.Array,
         alive = jnp.where(do_expand, valid_new, alive)
 
     return MJoinCount(count=total, overflowed=overflow,
-                      frontier=assign, alive=alive)
+                      frontier=assign, alive=alive, level_edges=k)
 
 
 def decode_tuples(res: MJoinCount, order, n_nodes: int):

@@ -62,7 +62,9 @@ class JaxGM:
     a served-path phase (``jaxgm.encode``, ``jaxgm.compile``,
     ``jaxgm.dispatch``, ``jaxgm.readback``): a profiler annotation timed
     into ``serve_phase_seconds`` of ``metrics`` (the engine's registry, or
-    a private one).
+    a private one).  ``jaxgm_mjoin_edge_trips`` counts the MJoin
+    constraint-loop trips each dispatch ran, ``jaxgm_mjoin_edge_slots`` the
+    ``max_q × max_e`` a loop over every edge at every level would run.
     :meth:`prepare` / :meth:`prepare_batch` compile and return the
     dispatch as a zero-argument callable, so callers can govern the
     dispatch alone (a compile error raises from ``prepare*``, never from
@@ -90,6 +92,8 @@ class JaxGM:
         reg = metrics if metrics is not None else MetricsRegistry()
         self._phase = {p: reg.histogram(PHASE_METRIC, phase=p)
                        for p in JAXGM_PHASES}
+        self._edge_trips = reg.counter("jaxgm_mjoin_edge_trips")
+        self._edge_slots = reg.counter("jaxgm_mjoin_edge_slots")
         PROCESS.install()
 
     def _prep(self, q: PatternQuery) -> tuple:
@@ -122,6 +126,12 @@ class JaxGM:
         self.calls += 1
         return out
 
+    def _count_trips(self, level_edges: np.ndarray) -> None:
+        """One dispatch's constraint-loop trips: per level the batch's
+        largest ``level_edges`` (a vmapped loop runs its longest lane)."""
+        self._edge_trips.inc(int(level_edges.max(axis=0).sum()))
+        self._edge_slots.inc(self.max_q * self.max_e)
+
     def prepare(self, q: PatternQuery, materialize: bool = False
                 ) -> Callable[[], JaxMatchResult]:
         with phase("jaxgm.encode", self._phase["jaxgm.encode"]):
@@ -131,6 +141,7 @@ class JaxGM:
         def run() -> JaxMatchResult:
             res, sizes, order = self._dispatch(fn, qt)
             with phase("jaxgm.readback", self._phase["jaxgm.readback"]):
+                self._count_trips(np.asarray(res.level_edges)[None])
                 tuples = None
                 if materialize:
                     tuples = decode_tuples(res, order, q.n)
@@ -157,6 +168,7 @@ class JaxGM:
             with phase("jaxgm.readback", self._phase["jaxgm.readback"]):
                 count = np.asarray(res.count)
                 over = np.asarray(res.overflowed)
+                self._count_trips(np.asarray(res.level_edges))
                 sizes = np.asarray(sizes)
                 return [JaxMatchResult(count=int(count[i]),
                                        overflowed=bool(over[i]),
